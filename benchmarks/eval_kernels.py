@@ -541,20 +541,25 @@ def kernel_autotune(quick=True) -> List[Dict]:
 # ------------------------------------------------------------ compile cache
 
 _CACHE_PROBE = """
-import sys, time
+import time
+import jax
 from repro import ged
+from repro.ged.exec import enable_compile_cache
+enable_compile_cache()          # the directory comes from the environment
 pairs = [(([0, 1, 1], [(0, 1, 1), (1, 2, 2)]),
           ([0, 1, 2], [(0, 1, 1), (0, 2, 1)]))]
-eng = ged.GedEngine("jax", cache=False, pool=64, max_iters=64,
-                    compile_cache_dir=sys.argv[1])
+eng = ged.GedEngine("jax", cache=False, pool=64, max_iters=64)
 t0 = time.perf_counter(); eng.compute(pairs)
 first = time.perf_counter() - t0
 t0 = time.perf_counter(); eng.compute(pairs)
 steady = time.perf_counter() - t0
 s = eng.stats
+d = jax.devices()[0]
 print(f"RESULT first={first} steady={steady} "
       f"hits={s['persistent_cache_hits']} "
-      f"misses={s['persistent_cache_misses']}")
+      f"misses={s['persistent_cache_misses']} "
+      f"platform={d.platform} kind={d.device_kind.replace(' ', '_')} "
+      f"count={len(jax.devices())}")
 """
 
 
@@ -562,24 +567,36 @@ def kernel_compile_cache(quick=True) -> List[Dict]:
     """Warm-vs-cold first-call compile across processes.
 
     Two fresh subprocesses run the same tiny engine workload against one
-    persistent compilation cache directory: the first pays the XLA
-    compile and serialises it, the second deserialises.  The remaining
-    warm first-call time is tracing + dispatch, which the persistent
-    cache cannot remove.
+    persistent compilation cache directory, placed through
+    ``JAX_COMPILATION_CACHE_DIR``: the first pays the XLA compile and
+    serialises it, the second deserialises.  The remaining warm
+    first-call time is tracing + dispatch, which the persistent cache
+    cannot remove.  The children run on whatever platform the
+    environment gives them, and each row names it.
+
+    A device belongs to one process at a time, so this must run before
+    the calling process initialises a JAX backend (``benchmarks/run.py``
+    runs it first); it refuses to run after.
     """
+    from jax._src import xla_bridge
+    if xla_bridge.backends_are_initialized():
+        raise RuntimeError(
+            "kernel_compile_cache starts processes that need the device; "
+            "run it before this process initialises a JAX backend")
     rows = []
     with tempfile.TemporaryDirectory() as d:
         for run in ("cold", "warm"):
             env = dict(os.environ)
-            env.setdefault("JAX_PLATFORMS", "cpu")
+            env["JAX_COMPILATION_CACHE_DIR"] = d
             root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
             env["PYTHONPATH"] = os.path.join(root, "src") + (
                 os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
             out = subprocess.run(
-                [sys.executable, "-c", _CACHE_PROBE, d],
+                [sys.executable, "-c", _CACHE_PROBE],
                 capture_output=True, text=True, env=env, check=True)
             m = re.search(r"RESULT first=(\S+) steady=(\S+) hits=(\S+) "
-                          r"misses=(\S+)", out.stdout)
+                          r"misses=(\S+) platform=(\S+) kind=(\S+) "
+                          r"count=(\S+)", out.stdout)
             assert m, out.stdout + out.stderr
             rows.append({
                 "run": run,
@@ -587,15 +604,20 @@ def kernel_compile_cache(quick=True) -> List[Dict]:
                 "steady_s": float(m.group(2)),
                 "persistent_cache_hits": float(m.group(3)),
                 "persistent_cache_misses": float(m.group(4)),
+                "platform": m.group(5),
+                "device_kind": m.group(6).replace("_", " "),
+                "devices": int(m.group(7)),
             })
     assert rows[0]["persistent_cache_misses"] >= 1, rows
     assert rows[1]["persistent_cache_hits"] >= 1, rows
     print_table("Persistent compile cache: cold vs warm process", rows,
-                ["run", "first_call_s", "steady_s",
-                 "persistent_cache_hits", "persistent_cache_misses"])
+                ["run", "platform", "device_kind", "first_call_s",
+                 "steady_s", "persistent_cache_hits",
+                 "persistent_cache_misses"])
     record_section("BENCH_engine", "compile_cache", rows)
     return rows
 
 
-ALL = (kernel_hotpath, kernel_roofline, kernel_autotune,
-       kernel_compile_cache)
+# kernel_compile_cache is not here: it must run before the process touches
+# JAX, which benchmarks/run.py arranges.
+ALL = (kernel_hotpath, kernel_roofline, kernel_autotune)

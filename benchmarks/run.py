@@ -6,6 +6,10 @@ and the roofline summary from the dry-run artifacts.
   PYTHONPATH=src python -m benchmarks.run --quick    # same, explicit (CI)
   PYTHONPATH=src python -m benchmarks.run --full     # larger sizes
   PYTHONPATH=src python -m benchmarks.run --only eval5,engine
+
+jax's persistent compile cache is on for the whole run (the directory is
+``JAX_COMPILATION_CACHE_DIR`` when set, else ``.jax_cache/`` in the
+checkout).
 """
 
 from __future__ import annotations
@@ -17,6 +21,7 @@ import traceback
 from benchmarks import eval_engine, eval_kernels, eval_paper
 from benchmarks.roofline import load as roofline_load, load_ged, \
     markdown, markdown_ged
+from repro.ged.exec import enable_compile_cache
 
 
 def main() -> None:
@@ -28,7 +33,7 @@ def main() -> None:
     ap.add_argument("--only", default="",
                     help="comma list: eval1..eval9, engine, index, "
                          "deadline, persistence, kernels, eval_kernels, "
-                         "roofline")
+                         "compile_cache, roofline")
     args = ap.parse_args()
     if args.full and args.quick:
         ap.error("--full and --quick are mutually exclusive")
@@ -48,6 +53,16 @@ def main() -> None:
 
     t0 = time.time()
     failures = []
+
+    # The compile-cache probe starts processes that need the device, so it
+    # runs before this process touches JAX (one process per chip).
+    if want("compile_cache") or want("eval_kernels"):
+        try:
+            eval_kernels.kernel_compile_cache(quick=quick)
+        except Exception as e:
+            failures.append(("compile_cache", e))
+            traceback.print_exc()
+    enable_compile_cache()
 
     paper_map = {f"eval{i+1}": fn for i, fn in enumerate(eval_paper.ALL)}
     for tag, fn in paper_map.items():

@@ -11,6 +11,11 @@ import numpy as np
 
 from repro import ged
 from repro.core.exact.graph import Graph
+from repro.ged.exec import enable_compile_cache
+
+# jax's persistent compile cache: JAX_COMPILATION_CACHE_DIR when set, else
+# .jax_cache/ in the checkout — a second run skips the engine compiles
+enable_compile_cache()
 
 # --- build the paper's Figure 3 pair ---------------------------------------
 A, B, C = 0, 1, 2

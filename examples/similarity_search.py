@@ -19,6 +19,11 @@ import numpy as np
 
 from repro.data.graphs import aids_like_graph, perturb
 from repro.ged import GraphStore
+from repro.ged.exec import enable_compile_cache
+
+# jax's persistent compile cache: JAX_COMPILATION_CACHE_DIR when set, else
+# .jax_cache/ in the checkout — a second run skips the engine compiles
+enable_compile_cache()
 
 rng = np.random.default_rng(1)
 

@@ -61,24 +61,6 @@ def _run_batch(qv, gv, qa, ga, order, n, taus, cfg: EngineConfig,
     return jax.vmap(one)(qv, gv, qa, ga, order, n, taus)
 
 
-def dispatch_packed(packed: GraphPairTensors, taus, cfg: EngineConfig,
-                    verification: bool) -> Dict[str, jax.Array]:
-    """Enqueue one engine invocation; return un-materialised device arrays.
-
-    The raw compute step under :mod:`repro.ged.exec` — no deprecation
-    shimming, no rounding policy, just pack-in / futures-out.  JAX
-    dispatches asynchronously: this returns as soon as the computation is
-    queued on the device, with every value still a ``jax.Array`` future.
-    ``repro.ged.exec.PendingBatch`` wraps the dict (blocking ``result()``
-    converts to numpy); the overlapped ``auto`` scheduler in
-    :mod:`repro.ged.backends` does useful work before reading the numbers.
-    """
-    args = pair_tuple(packed)
-    return _run_batch(*args, jnp.asarray(np.asarray(taus, dtype=np.float32)),
-                      cfg, bool(verification), packed.n_vlabels,
-                      packed.n_elabels)
-
-
 def ged_batch(pairs: GraphPairTensors, cfg: EngineConfig = EngineConfig()
               ) -> Dict[str, np.ndarray]:
     """Exact-with-certificate GED for a batch of pairs.

@@ -36,6 +36,15 @@ import jax.numpy as jnp
 from repro.kernels import ops as kops
 
 BIG = 1e7
+# Cap on one bid increment.  Only a row whose second-best column is
+# forbidden (a BIG entry of the GED cost matrices) bids more than any
+# finite cost spread, and pricing a column ~BIG buys nothing: it only
+# destroys the f32 arithmetic of the dual bounds (at ~1e7 the spacing is
+# 1.0, so sum(prices) rounds and a forced bound can overshoot OPT — an
+# inadmissible bound that prunes the optimal branch).  Capped, every price
+# stays a small dyadic rational and the bounds are computed exactly; weak
+# duality holds for any prices, so admissibility never depended on them.
+MAX_BID = 1e3
 
 
 class AuctionState(NamedTuple):
@@ -71,7 +80,7 @@ def auction_sweep(cost: jnp.ndarray, st: AuctionState, eps: float) -> AuctionSta
     n = cost.shape[-1]
     unassigned = st.row_to_col < 0                     # (..., N)
     m1, a1, m2 = kops.reduced_top2(cost, st.prices)    # fused kernel
-    incr = (m2 - m1) + eps                             # bid increment per row
+    incr = jnp.minimum(m2 - m1, MAX_BID) + eps         # bid increment per row
     incr = jnp.where(unassigned, incr, -BIG)           # only unassigned bid
 
     # Resolve conflicts: per column, the bidding row with the largest

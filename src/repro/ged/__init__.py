@@ -45,8 +45,8 @@ from repro.ged.backends import (available_backends, make_backend,
 from repro.ged.exec import (Executor, PendingBatch, ResultCache,
                             ShardedExecutor, SketchSpec, batch_signatures,
                             graph_digest, wl_digest, wl_signature)
-from repro.ged.faults import (Deadline, FaultInjector, InjectedFault,
-                              Overloaded, RetryPolicy)
+from repro.ged.faults import (CompileRefusal, Deadline, FaultInjector,
+                              InjectedFault, Overloaded, RetryPolicy)
 from repro.ged.index import CandidateIndex, sketch_damage
 from repro.ged.plan import as_graph, build_plan, slot_bucket
 from repro.ged.results import GedOutcome, SearchHit
@@ -80,5 +80,6 @@ __all__ = [
     "RetryPolicy",
     "FaultInjector",
     "InjectedFault",
+    "CompileRefusal",
     "Overloaded",
 ]

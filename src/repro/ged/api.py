@@ -85,14 +85,16 @@ class GedEngine:
         with every certified outcome, shared safely between concurrent
         processes.  Counters appear in :attr:`stats` as
         ``shared_cache_*``.
-    compile_cache_dir : directory for jax's *persistent* compilation
-        cache (default: the ``REPRO_GED_COMPILE_CACHE_DIR`` environment
-        variable; unset means off).  Compiled engine executables are
-        serialised there and re-loaded by later processes, so the
-        multi-second first-call compile is paid once per machine rather
-        than once per process.  Process-global (jax has one cache);
-        hit/miss/entry counters appear in :attr:`stats` as
-        ``persistent_cache_*``.
+    compile_cache_dir : turn on jax's *persistent* compilation cache in
+        this directory (default ``None``: the engine leaves the cache as
+        the process set it up, see
+        :func:`repro.ged.exec.enable_compile_cache`).  A
+        ``JAX_COMPILATION_CACHE_DIR`` set by the caller takes precedence.
+        Compiled engine executables are serialised there and re-loaded by
+        later processes, so the multi-second first-call compile is paid
+        once per machine rather than once per process.  Process-global
+        (jax has one cache); hit/miss/entry counters appear in
+        :attr:`stats` as ``persistent_cache_*``.
     autotune_dir : directory for the measured kernel-tuning table
         (default: the ``REPRO_GED_AUTOTUNE_DIR`` environment variable;
         unset means in-memory only).  ``use_kernel="auto"`` resolves each
@@ -208,7 +210,8 @@ class GedEngine:
             raise ValueError(f"unknown digest {digest!r}; "
                              f"expected one of {sorted(DIGESTS)}")
         self.digest = digest
-        self.compile_cache_dir = enable_compile_cache(compile_cache_dir)
+        self.compile_cache_dir = (enable_compile_cache(compile_cache_dir)
+                                  if compile_cache_dir is not None else None)
         self.autotune_dir = enable_autotune(autotune_dir)
         if config is None:
             config = EngineConfig(**{"use_kernel": False, **config_overrides})

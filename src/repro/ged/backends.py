@@ -251,6 +251,8 @@ class EngineBackend:
                 pending = self.executor.run_bucket_async(
                     bucket, taus, cfg, verification, ctx=ctx, rung=0)
                 out = pending.result()
+            except _faults.CompileRefusal:
+                raise
             except Exception as exc:
                 # the engine rung is permanently gone for this bucket
                 # (kernel AND unfused dispatch failed): the degradation
@@ -471,6 +473,8 @@ class AutoBackend:
                 pending = self.executor.run_packed_async(
                     bucket.packed, bucket.pad_values(taus), rcfg,
                     verification, real=bucket.real, ctx=ctx, rung=rung)
+            except _faults.CompileRefusal:
+                raise
             except Exception as exc:
                 degrade_bucket(bucket, exc)
                 return
@@ -481,13 +485,16 @@ class AutoBackend:
                 drain(item)             # sequential baseline: block now
 
         def drain(item: _InFlight) -> None:
-            # Never raises: a batch that fails at materialisation is
-            # degraded to the host solver, so callers (including the
-            # cleanup path below) can always drain in-flight work.
+            # Raises only a compile refusal (of the unfused re-dispatch): a
+            # batch that fails at materialisation is degraded to the host
+            # solver, so callers (including the cleanup path below) can
+            # always drain in-flight work.
             nonlocal last_block_end
             t_drain = time.perf_counter()
             try:
                 out = item.pending.result()  # blocks until the batch lands
+            except _faults.CompileRefusal:
+                raise
             except Exception as exc:
                 last_block_end = time.perf_counter()
                 degrade_bucket(item.bucket, exc)
@@ -556,7 +563,8 @@ class AutoBackend:
         finally:
             # Never strand dispatched device work or lose its survivor
             # bounds — on deadline expiry or a mid-flight error, drain
-            # everything still in flight (drain() itself cannot raise).
+            # everything still in flight (drain() raises only a compile
+            # refusal, which fails the run anyway).
             while inflight:
                 drain(inflight.popleft())
         if expired or any(r is None for r in results):

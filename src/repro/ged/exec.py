@@ -50,7 +50,13 @@ from repro.ged.results import GedOutcome, engine_mapping
 
 # ------------------------------------------------- persistent compile cache
 
-COMPILE_CACHE_ENV = "REPRO_GED_COMPILE_CACHE_DIR"
+# Where the persistent compile cache lives when the caller did not place it
+# with ``JAX_COMPILATION_CACHE_DIR``: one fixed directory inside the
+# checkout.  The path is never built from a temporary name, a process id or
+# the time — a cache that moves is never hit again.
+DEFAULT_COMPILE_CACHE_DIR = os.path.normpath(os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), "..", "..", "..",
+    ".jax_cache"))
 
 # Process-wide persistent-cache state: the enabled directory plus hit/miss
 # counters fed by jax's monitoring events.  jax's compilation cache is a
@@ -71,42 +77,45 @@ def _cache_event_listener(event: str, **kw) -> None:
         _PERSISTENT_CACHE["misses"] += 1        # type: ignore[operator]
 
 
-def enable_compile_cache(cache_dir: Optional[str] = None) -> Optional[str]:
-    """Point jax's persistent compilation cache at ``cache_dir``.
+def enable_compile_cache(cache_dir: Optional[str] = None) -> str:
+    """Turn on jax's persistent compilation cache; return its directory.
 
-    ``cache_dir`` defaults to the ``REPRO_GED_COMPILE_CACHE_DIR``
-    environment variable; when neither is set this is a no-op returning
-    ``None``.  Compiled engine executables are serialised into the
-    directory and re-loaded by *later processes*, so the multi-second
-    first-call compile is paid once per machine.  Idempotent — repeat
-    calls (every ``GedEngine(compile_cache_dir=...)``) just re-point the
-    directory.  Hit/miss counts land in :func:`persistent_cache_stats`
-    (and therefore ``engine.stats``).
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is the directory: jax reads
+    it itself, and nothing here sets another one.  Otherwise the cache
+    goes to ``cache_dir``, or by default to :data:`DEFAULT_COMPILE_CACHE_DIR`
+    (``.jax_cache/`` at the root of the checkout).  Compiled engine
+    executables are serialised there and re-loaded by *later processes*,
+    so the multi-second first-call compile is paid once per machine.
+    Entry points call this at start-up; importing the library enables
+    nothing.  Idempotent — repeat calls (every
+    ``GedEngine(compile_cache_dir=...)``) just re-point the directory.
+    Hit/miss counts land in :func:`persistent_cache_stats` (and therefore
+    ``engine.stats``).
 
-    >>> enable_compile_cache(None) is None     # no dir, no env: no-op
-    True
+    >>> os.path.basename(DEFAULT_COMPILE_CACHE_DIR)
+    '.jax_cache'
     """
-    path = cache_dir or os.environ.get(COMPILE_CACHE_ENV)
-    if not path:
-        return None
     import jax
     from jax import monitoring
+    outside = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    path = str(outside or cache_dir or DEFAULT_COMPILE_CACHE_DIR)
     if not _PERSISTENT_CACHE["listener"]:
         monitoring.register_event_listener(_cache_event_listener)
         _PERSISTENT_CACHE["listener"] = True
     os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", str(path))
+    if not outside:
+        jax.config.update("jax_compilation_cache_dir", path)
     # the engine's jit is exactly the compile worth persisting — don't let
     # the default 1s threshold skip mid-sized bucket shapes
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    if _PERSISTENT_CACHE["dir"] != str(path):
+    if _PERSISTENT_CACHE["dir"] != path:
         # jax latches its cache-enabled check at the first compile of the
         # process; (re-)pointing the directory afterwards needs an explicit
         # reset or the new setting is silently ignored
         from jax.experimental.compilation_cache import compilation_cache
         compilation_cache.reset_cache()
-    _PERSISTENT_CACHE["dir"] = str(path)
-    return str(path)
+    _PERSISTENT_CACHE["dir"] = path
+    return path
 
 
 def persistent_cache_stats() -> Dict[str, float]:
@@ -222,6 +231,7 @@ class Executor:
     def __init__(self) -> None:
         self.cache = CompileCache()
         self.stats: Dict[str, float] = {"calls": 0, "pairs": 0}
+        self._executables: Dict[tuple, object] = {}
 
     @property
     def batch_multiple(self) -> int:
@@ -280,10 +290,14 @@ class Executor:
                          rung) -> PendingBatch:
         """Dispatch with the retry policy and kernel-degradation ladder.
 
-        Transient failures retry with exponential backoff + jitter
-        (:class:`repro.ged.faults.RetryPolicy`); permanent kernel
-        compile/runtime failures fall back to the bit-identical unfused
-        config (``use_kernel=False``); a failure of the unfused path too
+        A program the Pallas lowering or the XLA compiler refuses raises
+        :class:`repro.ged.faults.CompileRefusal` and propagates: that is a
+        fault in the program, and degrading would hide it.  Runtime and
+        injected faults take the ladder: transient ones retry with
+        exponential backoff + jitter
+        (:class:`repro.ged.faults.RetryPolicy`); permanent kernel failures
+        fall back to the bit-identical unfused config
+        (``use_kernel=False``); a failure of the unfused path too
         propagates, and the backend above degrades the bucket to the host
         solver.  On a clean dispatch this is exactly the legacy path —
         the try/except costs nothing unless something raises.
@@ -342,6 +356,8 @@ class Executor:
                         check = (lambda: inj.check("result", rung))
                     return PendingBatch(arrays, recover=recover,
                                         check=check, flags=flags)
+                except _faults.CompileRefusal:
+                    raise
                 except Exception as exc:
                     last_exc = exc
                     if (_faults.classify_transient(exc)
@@ -404,8 +420,40 @@ class Executor:
                 "(GedEngine does this automatically)")
 
     def _dispatch(self, packed, taus, cfg, verification):
-        """Enqueue the device work; dict of un-materialised jax arrays."""
-        return engine_api.dispatch_packed(packed, taus, cfg, verification)
+        """Enqueue the device work; dict of un-materialised jax arrays.
+
+        Each program is lowered and compiled ahead of its first call
+        (:meth:`_compile`), so a refusal by the Pallas lowering or the XLA
+        compiler surfaces as :class:`repro.ged.faults.CompileRefusal`,
+        apart from the runtime faults the degradation ladder handles.
+        JAX dispatches asynchronously: this returns as soon as the
+        computation is queued, with every value still a ``jax.Array``
+        future.
+        """
+        import jax.numpy as jnp
+        args = engine_api.pair_tuple(packed) + (
+            jnp.asarray(np.asarray(taus, dtype=np.float32)),)
+        key = (cfg, bool(verification), packed.n_vlabels, packed.n_elabels,
+               packed.slots, packed.batch)
+        exe = self._executables.get(key)
+        if exe is None:
+            exe = self._compile(key[:4], args)
+            self._executables[key] = exe
+        return exe(*args)
+
+    def _compile(self, static: tuple, args: tuple):
+        """Lower and compile the engine program for ``args``' shapes."""
+        from repro.ged.faults import CompileRefusal
+        try:
+            return self._lower(static, args).compile()
+        except Exception as exc:
+            raise CompileRefusal(
+                f"{self.name} executor: the engine program for "
+                f"{tuple(a.shape for a in args)} was refused by the "
+                f"compiler: {exc}") from exc
+
+    def _lower(self, static: tuple, args: tuple):
+        return engine_api._run_batch.lower(*args, *static)
 
 
 class ShardedExecutor(Executor):
@@ -447,7 +495,6 @@ class ShardedExecutor(Executor):
             axes = pairs_axes(mesh)
         self.axes = tuple(axes)
         self.stats["single_device_fastpath"] = 0
-        self._fns: Dict[tuple, object] = {}
 
     @property
     def batch_multiple(self) -> int:
@@ -455,34 +502,29 @@ class ShardedExecutor(Executor):
         return default_rules(self.mesh).mesh_size(self.axes)
 
     def _dispatch(self, packed, taus, cfg, verification):
-        import jax
-        import jax.numpy as jnp
-
         if self.batch_multiple == 1:
-            # one shard = nothing to partition: skip the shard_map wrapper
-            # (and its trace/lowering overhead) entirely
             self.stats["single_device_fastpath"] += 1
-            return engine_api.dispatch_packed(packed, taus, cfg,
-                                              verification)
+        return super()._dispatch(packed, taus, cfg, verification)
 
-        key = (cfg, bool(verification), packed.n_vlabels, packed.n_elabels)
-        fn = self._fns.get(key)
-        if fn is None:
-            from jax.sharding import PartitionSpec as P
+    def _lower(self, static: tuple, args: tuple):
+        if self.batch_multiple == 1:
+            # one shard = nothing to partition: the plain single-device
+            # program, no shard_map wrapper (or its trace/lowering cost)
+            return super()._lower(static, args)
+        import jax
+        from jax.sharding import PartitionSpec as P
 
-            from repro.parallel.ops import shard_map
-            spec = P(self.axes)  # leading (batch) dim sharded, rest local
+        from repro.parallel.ops import shard_map
+        spec = P(self.axes)  # leading (batch) dim sharded, rest local
 
-            def local_shard(qv, gv, qa, ga, order, n, t):
-                return engine_api._run_batch(qv, gv, qa, ga, order, n, t,
-                                             *key)
+        def local_shard(qv, gv, qa, ga, order, n, t):
+            return engine_api._run_batch(qv, gv, qa, ga, order, n, t,
+                                         *static)
 
-            fn = jax.jit(shard_map(local_shard, mesh=self.mesh,
-                                   in_specs=(spec,) * 7, out_specs=spec,
-                                   check=False))
-            self._fns[key] = fn
-        args = engine_api.pair_tuple(packed)
-        return fn(*args, jnp.asarray(np.asarray(taus, dtype=np.float32)))
+        fn = jax.jit(shard_map(local_shard, mesh=self.mesh,
+                               in_specs=(spec,) * 7, out_specs=spec,
+                               check=False))
+        return fn.lower(*args)
 
 
 # ----------------------------------------------------------- result unpack

@@ -39,9 +39,9 @@ import numpy as np
 
 __all__ = [
     "Deadline", "RetryPolicy", "RunContext", "FaultInjector",
-    "InjectedFault", "Overloaded", "cheap_lower_bound", "fallback_outcome",
-    "classify_transient", "get_injector", "install_injector", "warn_once",
-    "FAULT_INJECT_ENV",
+    "InjectedFault", "CompileRefusal", "Overloaded", "cheap_lower_bound",
+    "fallback_outcome", "classify_transient", "get_injector",
+    "install_injector", "warn_once", "FAULT_INJECT_ENV",
 ]
 
 FAULT_INJECT_ENV = "REPRO_GED_FAULT_INJECT"
@@ -121,6 +121,19 @@ class Deadline:
         return child
 
 
+# ------------------------------------------------------ compile refusals
+
+class CompileRefusal(RuntimeError):
+    """The Pallas lowering or the XLA compiler refused an engine program.
+
+    Raised by the executors (the original error is the ``__cause__``) and
+    never degraded: a program the compiler refuses is a fault in the
+    program, and falling back to another path would hide that the
+    intended one cannot run on this device.  It propagates out of
+    ``compute``/``verify``.
+    """
+
+
 # ------------------------------------------------------ fault injection
 
 class InjectedFault(RuntimeError):
@@ -162,7 +175,7 @@ class FaultInjector:
     Specs are ``site[@key=value,...]`` joined by ``;``.  Sites:
 
     * ``dispatch`` — executor dispatch of a packed bucket;
-    * ``kernel``   — Pallas kernel compile/runtime (fires only when the
+    * ``kernel``   — a Pallas kernel runtime fault (fires only when the
       dispatched config has kernels enabled);
     * ``result``   — materialisation of a dispatched batch
       (``PendingBatch.result()``);
@@ -266,9 +279,10 @@ def classify_transient(exc: BaseException) -> bool:
 
     Injected faults carry their own kind; real-world transients are
     resource/communication shaped (OOM pressure, interrupted syscalls,
-    runner hiccups).  Compile/lowering errors are permanent by
-    construction — retrying the same trace cannot succeed, so they go
-    straight to the degradation ladder.
+    runner hiccups).  Only runtime and injected faults are classified
+    here: a lowering or compile refusal never reaches the ladder — the
+    executors raise it as :class:`CompileRefusal`, which propagates out
+    of the dispatch unretried and undegraded.
 
     >>> classify_transient(InjectedFault("dispatch", transient=True))
     True

@@ -745,6 +745,12 @@ class GraphStore:
             if i >= len(seeds) and lb_of[order[i]] > kth:
                 break
             reps = order[i:i + chunk]
+            if i >= len(seeds):
+                # the remainder is lb-sorted: stop the chunk where the
+                # cutoff bites, so no candidate that cannot enter the
+                # top k pays an exact computation (a far pair's exact
+                # GED can cost the host solver minutes)
+                reps = [rid for rid in reps if lb_of[rid] <= kth]
             t0 = time.perf_counter()
             outs = self.engine.compute(
                 [(q, self.graphs[rid]) for rid in reps], vocab=vocab)

@@ -61,7 +61,7 @@ class KernelDispatch:
     """A concrete per-bucket kernel plan: static, hashable, jit-key-safe.
 
     ``tile_* = 0`` means the kernel's own default tiling
-    (``gcd(N, 128)`` for LSa's candidate axis, ``min(N, 128)`` for BMa's
+    (``min(N, 128)`` for LSa's candidate axis and for BMa's
     block shape).
     """
 
@@ -301,6 +301,12 @@ def concrete_dispatch(cfg, n: int) -> KernelDispatch:
     """
     d = getattr(cfg, "dispatch", None)
     if d is not None:
+        # a plan's tiles are for the bucket it was made for: on an axis a
+        # tile does not divide, the kernel's default tiling stands in
+        fit = {f: _safe_tile(getattr(d, f), n)
+               for f in ("lsa_tile_u", "bma_tile_v", "bma_tile_u")}
+        if any(getattr(d, f) != t for f, t in fit.items()):
+            return dataclasses.replace(d, **fit)
         return d
     uk = cfg.use_kernel
     if uk == "auto":
@@ -415,7 +421,7 @@ def _tile_candidates(kernel: str, n: int) -> List[Tuple[int, int]]:
     """(tile_v, tile_u) sweep candidates; (0, 0) = the kernel default."""
     cands = [(0, 0)]
     if kernel == "lsa":
-        default = math.gcd(n, 128)
+        default = n if n <= 128 else math.gcd(n, 128)
         for t in (8, 32, 64):
             if n % t == 0 and t != default:
                 cands.append((0, t))

@@ -12,11 +12,18 @@ histogram Y, anchor mismatch counts) each round-tripping HBM.  The kernel
 tiles (v, u) into VMEM blocks and accumulates the label- and anchor-
 reductions with on-chip loops, writing ``lam`` once.
 
-TPU mapping notes (DESIGN.md §2): the (TV, TU) tile is VPU-aligned (lanes =
-128 on the u axis, sublanes on v); reductions over ``Le`` (edge labels) and
-``N`` (anchor positions) are unrolled ``fori_loop``s over VMEM-resident
-slices, so the working set is O(TV*N + TU*N) int32 + O(TV*TU) f32 per step —
-about 200 KiB at N=128, comfortably inside the ~16 MiB VMEM budget.
+TPU mapping notes: the (TV, TU) tile is VPU-aligned (lanes = 128 on the u
+axis, sublanes on v) and the batch axis is a squeezed (``None``) block
+dimension, so the (8, 128) tiling rule only ever sees full or aligned tile
+extents.  Mosaic cannot slice a value at a traced index, so the wrapper lays
+the operands out for the loops: v-side per-label histograms as ``(Le, TV,
+1)`` columns and u-side ones as ``(Le, 1, TU)`` rows (indexed by a static
+label on the leading axis), and the gathered g adjacency transposed to
+``(N, TU)`` so the anchor loop loads row ``j`` with a dynamic sublane
+slice.  The matching q column is a masked lane reduction of the ``(TV, N)``
+tile.  Working set per step: O(TV*N + TU*N) int32 + O(TV*TU) f32 plus the
+lane-padded label columns — about 1.4 MiB at N = 128, Le = 3 with
+double-buffered inputs, inside the 16 MiB of scoped VMEM.
 """
 
 from __future__ import annotations
@@ -28,41 +35,41 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _kernel(qv_ref, gv_ref, iq_ref, ig_ref, qa_ref, gc_ref, pa_ref, out_ref):
-    # Tile shapes: qv (1, TV), gv (1, TU), iq (1, TV, Le), ig (1, TU, Le),
-    # qa (1, TV, N), gc (1, TU, N), pa (1, N) -> out (1, TV, TU).
-    qv = qv_ref[0]            # (TV,)
-    gv = gv_ref[0]            # (TU,)
-    iq = iq_ref[0]            # (TV, Le)
-    ig = ig_ref[0]            # (TU, Le)
-    qa = qa_ref[0]            # (TV, N)
-    gc = gc_ref[0]            # (TU, N)
-    pa = pa_ref[0]            # (N,)
+def _kernel(qv_ref, gv_ref, iq_ref, ig_ref, qa_ref, gct_ref, pa_ref,
+            out_ref):
+    # Tile shapes: qv (TV, 1), gv (1, TU), iq (Le, TV, 1), ig (Le, 1, TU),
+    # qa (TV, N), gct (N, TU), pa (N, 1) -> out (TV, TU).
+    qa = qa_ref[...]
+    tv, n = qa.shape
+    tu = gct_ref.shape[1]
+    le = iq_ref.shape[0]
 
-    tv, le = iq.shape
-    tu = ig.shape[0]
-    n = qa.shape[1]
+    vmis = (qv_ref[...] != gv_ref[...]).astype(jnp.float32)
 
-    vmis = (qv[:, None] != gv[None, :]).astype(jnp.float32)
+    sq = jnp.zeros((tv, 1), jnp.float32)
+    sg = jnp.zeros((1, tu), jnp.float32)
+    inter = jnp.zeros((tv, tu), jnp.float32)
+    for l in range(le):
+        iql, igl = iq_ref[l], ig_ref[l]
+        sq, sg = sq + iql, sg + igl
+        inter = inter + jnp.minimum(iql, igl)
+    ups = jnp.maximum(sq, sg) - inter
 
-    sq = jnp.sum(iq, axis=1)  # (TV,)
-    sg = jnp.sum(ig, axis=1)  # (TU,)
-
-    def label_body(l, acc):
-        return acc + jnp.minimum(iq[:, l][:, None], ig[:, l][None, :])
-
-    inter = jax.lax.fori_loop(0, le, label_body,
-                              jnp.zeros((tv, tu), dtype=jnp.float32))
-    ups = jnp.maximum(sq[:, None], sg[None, :]) - inter
+    lanes = jax.lax.broadcasted_iota(jnp.int32, qa.shape, 1)
+    zero_u = jnp.zeros((1, tu), jnp.float32)
 
     def anchor_body(j, acc):
-        mism = (qa[:, j][:, None] != gc[:, j][None, :]).astype(jnp.float32)
-        return acc + mism * pa[j]
+        q_col = jnp.sum(jnp.where(lanes == j, qa, 0), axis=1, keepdims=True)
+        g_row = gct_ref[pl.ds(j, 1), :]                   # (1, TU)
+        # widen pa[j] along lanes first: Mosaic cannot broadcast a (1, 1)
+        # value over sublanes and lanes in one step
+        p_row = zero_u + pa_ref[pl.ds(j, 1), :]           # (1, TU)
+        return acc + jnp.where(q_col != g_row, p_row, 0.0)
 
     mism = jax.lax.fori_loop(0, n, anchor_body,
                              jnp.zeros((tv, tu), dtype=jnp.float32))
 
-    out_ref[0] = vmis + 0.5 * ups + mism
+    out_ref[...] = vmis + 0.5 * ups + mism
 
 
 @functools.partial(jax.jit, static_argnames=("tile_v", "tile_u", "interpret"))
@@ -79,6 +86,10 @@ def bma_cost_matrix_pallas(
     interpret: bool = False,
 ) -> jnp.ndarray:
     b, n = qv.shape
+    if inner_q.shape[-1] == 0:
+        # no edge labels: one all-zero label leaves Y unchanged and keeps
+        # every block non-empty
+        inner_q = inner_g = jnp.zeros((b, n, 1), jnp.float32)
     le = inner_q.shape[-1]
     tv = tile_v or min(n, 128)
     tu = tile_u or min(n, 128)
@@ -88,15 +99,19 @@ def bma_cost_matrix_pallas(
         _kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, tv), lambda b, i, j: (b, i)),
-            pl.BlockSpec((1, tu), lambda b, i, j: (b, j)),
-            pl.BlockSpec((1, tv, le), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, tu, le), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, tv, n), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, tu, n), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, n), lambda b, i, j: (b, 0)),
+            pl.BlockSpec((None, tv, 1), lambda bb, i, j: (bb, i, 0)),
+            pl.BlockSpec((None, 1, tu), lambda bb, i, j: (bb, 0, j)),
+            pl.BlockSpec((None, le, tv, 1), lambda bb, i, j: (bb, 0, i, 0)),
+            pl.BlockSpec((None, le, 1, tu), lambda bb, i, j: (bb, 0, 0, j)),
+            pl.BlockSpec((None, tv, n), lambda bb, i, j: (bb, i, 0)),
+            pl.BlockSpec((None, n, tu), lambda bb, i, j: (bb, 0, j)),
+            pl.BlockSpec((None, n, 1), lambda bb, i, j: (bb, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, tv, tu), lambda b, i, j: (b, i, j)),
+        out_specs=pl.BlockSpec((None, tv, tu), lambda bb, i, j: (bb, i, j)),
         out_shape=jax.ShapeDtypeStruct((b, n, n), jnp.float32),
         interpret=interpret,
-    )(qv, gv, inner_q, inner_g, qa_ord, gcross, pos_anch)
+        name="bma_cost_matrix",
+    )(qv.reshape(b, n, 1), gv.reshape(b, 1, n),
+      jnp.swapaxes(inner_q, 1, 2)[..., None],
+      jnp.swapaxes(inner_g, 1, 2)[:, :, None, :], qa_ord,
+      jnp.swapaxes(gcross, 1, 2), pos_anch.reshape(b, n, 1))

@@ -26,7 +26,6 @@ the (TX, NY) comparison tile — about 1 MiB at TX = 128, NY = 2048.
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
@@ -52,23 +51,31 @@ def rank_counts_pallas(x, y, *, strict=True, tile_x=0, interpret=False):
     required for correctness here (the counts are plain comparison sums)
     but is what makes the counts equal to searchsorted ranks downstream.
     """
-    b, nx = x.shape
+    b, n_real = x.shape
     ny = y.shape[-1]
-    tx = tile_x or math.gcd(nx, 128)
-    assert nx % tx == 0, (nx, tx)
+    # Mosaic's (8, 128) tiling rule: x tiles are 128 lanes (or the whole
+    # run when shorter), a run that is not a multiple of the tile (the pool
+    # remainder P - B) is padded and the padded counts dropped, and the
+    # runs travel as (B, 1, N) rows under a squeezed batch block dimension
+    tx = tile_x or min(n_real, 128)
+    x = jnp.pad(x, ((0, 0), (0, -n_real % tx)))
+    nx = x.shape[1]
     grid = (b, nx // tx)
     kern = functools.partial(_kernel, strict=strict)
-    return pl.pallas_call(
+    x_tile = pl.BlockSpec((None, 1, tx), lambda bb, i: (bb, 0, i))
+    out = pl.pallas_call(
         kern,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, tx), lambda bb, i: (bb, i)),
-            pl.BlockSpec((1, ny), lambda bb, i: (bb, 0)),
+            x_tile,
+            pl.BlockSpec((None, 1, ny), lambda bb, i: (bb, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, tx), lambda bb, i: (bb, i)),
-        out_shape=jax.ShapeDtypeStruct((b, nx), jnp.int32),
+        out_specs=x_tile,
+        out_shape=jax.ShapeDtypeStruct((b, 1, nx), jnp.int32),
         interpret=interpret,
-    )(x, y)
+        name="merge_rank_counts",
+    )(x.reshape(b, 1, nx), y.reshape(b, 1, ny))
+    return out.reshape(b, nx)[:, :n_real]
 
 
 def merge_ranks_pallas(keys_a, keys_b, *, tile_x=0, interpret=False):

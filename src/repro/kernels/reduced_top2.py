@@ -6,7 +6,9 @@ reduction avoids materialising the reduced (B, N, N) matrix in HBM twice.
 
 Tiling: rows (bidders) tiled to ``TR`` sublanes; the full column axis (N <=
 512) stays resident in VMEM lanes, so each grid step is one VMEM-local
-top-2 reduction.
+top-2 reduction.  The batch axis is a squeezed (``None``) block dimension
+and the per-row vectors travel as ``(B, 1, N)``, so no block has a 1 along
+a batch axis in its last two dimensions (Mosaic's (8, 128) tiling rule).
 """
 
 from __future__ import annotations
@@ -21,7 +23,8 @@ BIG = 1e7
 
 
 def _kernel(cost_ref, prices_ref, m1_ref, a1_ref, m2_ref):
-    cost = cost_ref[0]          # (TR, N)
+    # Tile shapes: cost (TR, N), prices (1, N) -> m1/a1/m2 (1, TR).
+    cost = cost_ref[...]        # (TR, N)
     prices = prices_ref[0]      # (N,)
     red = cost + prices[None, :]
     m1 = jnp.min(red, axis=1)
@@ -46,22 +49,21 @@ def reduced_top2_pallas(
     assert n % tr == 0
     grid = (b, n // tr)
     out_shapes = (
-        jax.ShapeDtypeStruct((b, n), jnp.float32),
-        jax.ShapeDtypeStruct((b, n), jnp.int32),
-        jax.ShapeDtypeStruct((b, n), jnp.float32),
+        jax.ShapeDtypeStruct((b, 1, n), jnp.float32),
+        jax.ShapeDtypeStruct((b, 1, n), jnp.int32),
+        jax.ShapeDtypeStruct((b, 1, n), jnp.float32),
     )
-    return pl.pallas_call(
+    row = pl.BlockSpec((None, 1, tr), lambda bb, i: (bb, 0, i))
+    m1, a1, m2 = pl.pallas_call(
         _kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, tr, n), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, n), lambda b, i: (b, 0)),
+            pl.BlockSpec((None, tr, n), lambda bb, i: (bb, i, 0)),
+            pl.BlockSpec((None, 1, n), lambda bb, i: (bb, 0, 0)),
         ],
-        out_specs=(
-            pl.BlockSpec((1, tr), lambda b, i: (b, i)),
-            pl.BlockSpec((1, tr), lambda b, i: (b, i)),
-            pl.BlockSpec((1, tr), lambda b, i: (b, i)),
-        ),
+        out_specs=(row, row, row),
         out_shape=out_shapes,
         interpret=interpret,
-    )(cost, prices)
+        name="reduced_top2",
+    )(cost, prices.reshape(b, 1, n))
+    return m1.reshape(b, n), a1.reshape(b, n), m2.reshape(b, n)

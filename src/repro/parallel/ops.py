@@ -24,25 +24,13 @@ import jax.numpy as jnp
 
 def shard_map(f: Optional[Callable] = None, *, mesh, in_specs, out_specs,
               check: bool = False):
-    """Version-portable ``shard_map`` (usable bare or as a decorator factory).
-
-    Newer jax exposes ``jax.shard_map`` (replication check flag spelled
-    ``check_vma``); this jaxlib only has ``jax.experimental.shard_map``
-    (spelled ``check_rep``).  Resolve whichever exists and translate the
-    ``check`` flag, so callers never touch the moving API surface.
-    """
-    import inspect
-
+    """``jax.shard_map`` with its replication check (``check_vma``) off by
+    default; usable bare or as a decorator factory."""
     import jax
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    params = inspect.signature(sm).parameters
-    flag = "check_vma" if "check_vma" in params else "check_rep"
 
     def wrap(fn: Callable) -> Callable:
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  **{flag: check})
+        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=check)
 
     return wrap if f is None else wrap(f)
 

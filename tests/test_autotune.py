@@ -169,6 +169,13 @@ def test_concrete_dispatch_is_pure_in_cfg():
     d = KernelDispatch(merge_fused=True)
     cfg = EngineConfig(use_kernel="auto", dispatch=d)
     assert autotune.concrete_dispatch(cfg, 16) is d
+    # tiles that do not divide the bucket's axis fall back to the default
+    tiled = KernelDispatch(lsa_fused=True, lsa_tile_u=8, bma_fused=True,
+                           bma_tile_v=8, bma_tile_u=8)
+    cfg3 = EngineConfig(use_kernel="auto", dispatch=tiled)
+    assert autotune.concrete_dispatch(cfg3, 16) is tiled
+    assert autotune.concrete_dispatch(cfg3, 4) == KernelDispatch(
+        lsa_fused=True, bma_fused=True)
     # unresolved "auto" at trace time -> the static heuristic, never the
     # table (the jit cache keys on cfg, not on mutable table state)
     cfg2 = EngineConfig(use_kernel="auto")

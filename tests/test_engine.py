@@ -195,3 +195,24 @@ def test_engine_unequal_sizes():
     ok = out["exact"]
     assert ok.mean() >= 0.8
     assert np.array_equal(out["ged"][ok].astype(int), want[ok])
+
+
+def test_bma_bound_admissible_when_graphs_fill_the_bucket():
+    """A graph that fills its slot bucket leaves a single dummy row and
+    column after its first anchor.  Bids against that row's forbidden
+    second-best column once inflated a price to ~1e7, where f32 rounding
+    made the forced dual bound overshoot OPT: a certified wrong reject
+    (pair 3 here).  Capped bids keep the bound exact and admissible."""
+    from repro import ged
+    from repro.core.exact.search import ged_verify
+    from repro.data.graphs import aids_like_graph
+    rng = np.random.default_rng(0)
+    pairs, taus = [], []
+    for _ in range(4):
+        q = aids_like_graph(rng, int(rng.choice([16, 32])))
+        k = int(rng.integers(1, 5))
+        pairs.append((q, perturb(rng, q, k, 62, 3)))
+        taus.append(float(k))
+    outs = ged.GedEngine("jax", slots=32, cache=False).verify(pairs, taus)
+    for i, (o, (q, g), tau) in enumerate(zip(outs, pairs, taus)):
+        assert o.certified and o.similar == ged_verify(q, g, tau).similar, i

@@ -235,6 +235,35 @@ def test_rung_scoped_fault_leaves_other_rungs_alone():
         assert a.similar == b.similar and b.certified
 
 
+@pytest.mark.parametrize("backend", ["pallas", "auto"])
+def test_kernel_lowering_refusal_propagates(monkeypatch, backend):
+    """A kernel the Pallas lowering refuses is a fault in the program: it
+    propagates out of compute/verify as CompileRefusal — no retry, no
+    unfused fallback, no host-solver rescue.  Steering the kernels off
+    interpret mode on the CPU makes the lowering refuse them for real."""
+    import jax
+
+    from repro.kernels import ops
+    pairs = _pairs(3)
+    eng = ged.GedEngine(backend, use_kernel=True, **ENGINE_OPTS)
+    jax.clear_caches()
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    try:
+        with pytest.raises(ged.CompileRefusal) as ei:
+            eng.verify(pairs, 1.0)
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()       # no trace with interpret=False may leak
+    assert ei.value.__cause__ is not None
+    stats = eng.stats
+    assert not any(stats.get(k) for k in ("retries", "degraded_kernel",
+                                          "degraded_host",
+                                          "executor_degraded_kernel",
+                                          "executor_retries"))
+    # the same engine answers once the kernels lower again
+    assert len(eng.verify(pairs, 1.0)) == len(pairs)
+
+
 def test_fault_injector_spec_parsing():
     with pytest.raises(ValueError, match="unknown fault site"):
         FaultInjector("badsite@times=1")

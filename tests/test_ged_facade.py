@@ -357,11 +357,49 @@ def test_compile_cache_dir_knob(tmp_path, _compile_cache_reset):
 
 def test_compile_cache_env_default(tmp_path, monkeypatch,
                                    _compile_cache_reset):
-    from repro.ged.exec import COMPILE_CACHE_ENV, enable_compile_cache
+    """A JAX_COMPILATION_CACHE_DIR set from outside is the cache directory,
+    whatever the caller passes."""
+    import jax
+
+    from repro.ged.exec import enable_compile_cache
     d = str(tmp_path / "env_cc")
-    monkeypatch.setenv(COMPILE_CACHE_ENV, d)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", d)
+    jax.config.update("jax_compilation_cache_dir", d)   # as jax reads it
     assert enable_compile_cache(None) == d
     assert os.path.isdir(d)
+    # an explicit directory does not override the caller's variable, and
+    # nothing in the code points jax anywhere else
+    assert enable_compile_cache(str(tmp_path / "other")) == d
+    assert jax.config.jax_compilation_cache_dir == d
+    assert not os.path.exists(tmp_path / "other")
+
+
+def test_compile_cache_unset_default_is_fixed_checkout_path(
+        monkeypatch, _compile_cache_reset):
+    """Unset, the cache lands in the fixed .jax_cache/ at the checkout's
+    root — never a temporary, per-process or time-stamped path."""
+    import jax
+
+    from repro.ged.exec import DEFAULT_COMPILE_CACHE_DIR, enable_compile_cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    root = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
+    assert DEFAULT_COMPILE_CACHE_DIR == os.path.join(root, ".jax_cache")
+    assert enable_compile_cache() == DEFAULT_COMPILE_CACHE_DIR
+    assert jax.config.jax_compilation_cache_dir == DEFAULT_COMPILE_CACHE_DIR
+
+
+def test_engine_enables_no_cache_by_default(_compile_cache_reset):
+    """Constructing an engine without compile_cache_dir= turns nothing on:
+    only entry points enable the persistent cache."""
+    import jax
+
+    from repro.ged import exec as gexec
+    before = (gexec._PERSISTENT_CACHE["dir"],
+              jax.config.jax_compilation_cache_dir)
+    eng = ged.GedEngine("jax", **ENGINE_OPTS)
+    assert eng.compile_cache_dir is None
+    assert (gexec._PERSISTENT_CACHE["dir"],
+            jax.config.jax_compilation_cache_dir) == before
 
 
 def test_shard_padding_round_trip():

@@ -209,7 +209,8 @@ def _sorted_runs(rng, b, na, nb, lo=0, hi=6):
                                      (1, 1016, 64), (2, 504, 128)])
 def test_merge_ranks_kernel_sweep(b, na, nb):
     """Counts match the oracle AND numpy searchsorted on arbitrary run
-    lengths (1016 and 504 exercise the gcd tile fallback: gcd(.,128)=8)."""
+    lengths (1016 and 504 exercise the padded last tile: 128-lane tiles
+    over a run that is not a multiple of 128)."""
     rng = np.random.default_rng(b * 1000 + na + nb)
     ka, kb = _sorted_runs(rng, b, na, nb)
     ca, cb = merge_ranks_pallas(ka, kb, interpret=True)

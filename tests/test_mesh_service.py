@@ -1,9 +1,10 @@
 """GED verification under real (fake-device) mesh sharding.
 
 The dry-run proves the 512-chip lowering; this test EXECUTES the batched
-engine with the pair batch sharded over 8 devices and checks answers are
-identical to the single-device run (lockstep vmap semantics are
-placement-invariant).
+engine through ``GedEngine("sharded", mesh=...)`` with the pair batch
+sharded over 8 devices and checks that the answers are identical to the
+single-device run (lockstep vmap semantics are placement-invariant) and
+that the engine outputs stay sharded (no implicit gather).
 """
 
 import os
@@ -19,10 +20,7 @@ SCRIPT = textwrap.dedent("""
     os.environ["REPRO_DISABLE_PALLAS"] = "1"
     import sys; sys.path.insert(0, %r)
     import jax, numpy as np
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.core.engine.api import verify_batch, _run_batch, _pair_tuple
-    from repro.core.engine.search import EngineConfig
-    from repro.core.engine.tensor_graphs import pack_pairs
+    from repro import ged
     from repro.data.graphs import perturb, random_graph
 
     rng = np.random.default_rng(5)
@@ -30,29 +28,29 @@ SCRIPT = textwrap.dedent("""
     for _ in range(16):
         q = random_graph(rng, 10)
         pairs.append((q, perturb(rng, q, 3)))
-    packed = pack_pairs(pairs, slots=16)
-    cfg = EngineConfig(pool=256, expand=4, max_iters=256, bound="hybrid",
-                       strategy="astar", use_kernel=False)
-    taus = [4.0] * 16
+    opts = dict(slots=16, pool=256, expand=4, max_iters=256, cache=False)
 
     # single-device reference
-    ref = verify_batch(packed, taus, cfg)
+    ref = ged.GedEngine("jax", **opts).verify(pairs, 4.0)
 
-    # sharded execution: pairs over a (4, 2) mesh, all axes
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
-    sh = NamedSharding(mesh, P(("data", "model")))
-    import jax.numpy as jnp
-    args = [jax.device_put(jnp.asarray(a), NamedSharding(
-        mesh, P(("data", "model"), *([None] * (np.asarray(a).ndim - 1)))))
-        for a in _pair_tuple(packed)]
-    t = jax.device_put(jnp.asarray(np.asarray(taus, np.float32)), sh)
-    with mesh:
-        out = _run_batch(*args, t, cfg, True, packed.n_vlabels,
-                         packed.n_elabels)
-    for k in ("similar", "exact"):
-        np.testing.assert_array_equal(np.asarray(out[k]), ref[k])
-    # outputs stayed sharded (no implicit gather)
-    assert len(out["similar"].sharding.device_set) == 8
+    # sharded execution: the pair batch over an 8-device mesh
+    mesh = jax.make_mesh((8,), ("data",))
+    eng = ged.GedEngine("sharded", mesh=mesh, **opts)
+    out = eng.verify(pairs, 4.0)
+    for a, b in zip(ref, out):
+        assert (a.similar, a.certified) == (b.similar, b.certified)
+    assert eng.batch_multiple == 8
+    assert eng.stats["executor_single_device_fastpath"] == 0
+
+    # outputs stayed sharded: inspect the dispatched (un-materialised)
+    # device arrays of one bucket
+    ex = eng._backend.executor
+    plan = ged.build_plan(pairs, slots=16, batch_multiple=8)
+    bucket = plan.buckets[0]
+    arrays = ex._dispatch(bucket.packed,
+                          bucket.pad_values(np.full(16, 4.0, np.float32)),
+                          eng.config, True)
+    assert len(arrays["similar"].sharding.device_set) == 8
     print("OK")
 """)
 
