@@ -297,7 +297,6 @@ def engine_escalation_overlap(quick=True) -> List[Dict]:
             "escalated": s["escalated"],
             "host_solved": s["host_solved"],
             "dispatches": s["dispatches"],
-            "overlap_saved_s": s["overlap_saved_s"],
             "certified_frac": float(np.mean([o.certified for o in outs])),
         })
     assert outcomes["sequential"] == outcomes["overlapped"], \
@@ -306,7 +305,7 @@ def engine_escalation_overlap(quick=True) -> List[Dict]:
         "auto must certify every answer"
     print_table("Auto escalation: sequential vs overlapped rungs", rows,
                 ["mode", "pairs", "pairs_per_s", "wall_s", "escalated",
-                 "host_solved", "overlap_saved_s", "certified_frac"])
+                 "host_solved", "certified_frac"])
     record_section("BENCH_engine", "escalation_overlap", rows)
     return rows
 

@@ -40,6 +40,7 @@ from repro.ged.faults import (Deadline, FaultInjector, RetryPolicy,
                               RunContext)
 from repro.ged.plan import Vocab, as_graph, as_pairs, build_plan
 from repro.ged.results import GedOutcome
+from repro.ged.spans import span
 
 Taus = Union[float, Sequence[float]]
 
@@ -386,12 +387,12 @@ class GedEngine:
         """Backend + executor counters plus cache hit/miss totals.
 
         Per backend: the ``auto`` pipeline reports ``pairs`` /
-        ``escalated`` / ``host_solved`` / ``batches`` / ``dispatches``,
-        per-rung survivor counts (``survivors_rung_0``, ...) and
-        ``overlap_saved_s`` — device seconds hidden behind host-solver
-        and drain work by overlapped rung execution.  Every engine adds
-        ``executor_*``, ``compile_cache_*`` and ``result_cache_*``
-        counters where applicable, plus the kernel-dispatch telemetry:
+        ``escalated`` / ``host_solved`` / ``batches`` / ``dispatches`` and
+        per-rung survivor counts (``survivors_rung_0``, ...).  Every
+        engine adds ``executor_*`` (``executor_pairs`` real pairs
+        dispatched, ``executor_rows`` the padded batches they rode in),
+        ``compile_cache_*`` and ``result_cache_*`` counters where
+        applicable, plus the kernel-dispatch telemetry:
         ``autotune_hits`` / ``autotune_misses`` / ``autotune_sweep_s`` /
         ``autotune_entries`` and ``pallas_interpret`` (True when Pallas
         kernels fall back to interpret mode — CPU — so bench rows cannot
@@ -499,84 +500,86 @@ class GedEngine:
                 f"{self._kernel_default}")
         cfg = dataclasses.replace(self.config, **overrides) \
             if overrides else self.config
-        pairs = as_pairs(pairs)
-        n = len(pairs)
-        if verification:
-            taus = np.broadcast_to(
-                np.asarray(tau, dtype=np.float32), (n,)).copy()
-        else:
-            taus = np.zeros((n,), dtype=np.float32)
-
-        results: List[Optional[GedOutcome]] = [None] * n
-        run_idx = list(range(n))
-        keys: List[Optional[tuple]] = [None] * n
-        dup_of: Dict[int, int] = {}
-        if self._cache is not None or self._shared is not None:
-            run_idx, seen = [], {}
-            for i, (q, g) in enumerate(pairs):
-                keys[i] = pair_key(
-                    q, g, verification,
-                    float(taus[i]) if verification else None, cfg,
-                    self.backend, digest=self.digest)
-                if keys[i] in seen:
-                    # duplicate within this batch: runs once, answers twice
-                    dup_of[i] = seen[keys[i]]
-                    if self._cache is not None:
-                        self._cache.hits += 1
-                    continue
-                hit = self._cache.get(keys[i]) \
-                    if self._cache is not None else None
-                if hit is None and self._shared is not None:
-                    # the cross-process tier answers in-memory misses;
-                    # promote hits so this process stops paying disk
-                    hit = self._shared.get(keys[i])
-                    if hit is not None and self._cache is not None:
-                        self._cache.put(keys[i], self._cache_view(hit))
-                if hit is not None:
-                    results[i] = hit
-                else:
-                    seen[keys[i]] = i
-                    run_idx.append(i)
-
-        if run_idx:
-            plan = build_plan(
-                [pairs[i] for i in run_idx], slots=self.slots,
-                vocab=vocab if vocab is not None else self.vocab,
-                batch_multiple=self.batch_multiple)
-            dl = deadline_s if deadline_s is not None else self.deadline_s
-            pp = (per_pair_deadline_s if per_pair_deadline_s is not None
-                  else self.per_pair_deadline_s)
-            ctx = RunContext(
-                deadline=dl if isinstance(dl, Deadline) else Deadline(dl),
-                per_pair_deadline_s=pp,
-                injector=self._injector, retry=self._retry)
-            if self._backend_takes_ctx:
-                outs = self._backend.run(plan, taus[run_idx], verification,
-                                         cfg, ctx=ctx)
+        with span("ged.engine.run") as run_span:
+            pairs = as_pairs(pairs)
+            n = len(pairs)
+            if verification:
+                taus = np.broadcast_to(
+                    np.asarray(tau, dtype=np.float32), (n,)).copy()
             else:
-                outs = self._backend.run(plan, taus[run_idx], verification,
-                                         cfg)
-            for k, v in ctx.stats.items():
-                self._fault_stats[k] = self._fault_stats.get(k, 0) + v
-            for i, o in zip(run_idx, outs):
-                results[i] = o
-                # never cache a timed-out or fault-degraded *uncertified*
-                # answer: a later, unconstrained run must not be poisoned
-                # by this run's budget or faults (degraded-but-certified
-                # answers are bit-identical, so they stay cacheable)
-                if o.timed_out or (not o.certified
-                                   and o.stats.get("degraded")):
-                    continue
-                if self._cache is not None:
-                    self._cache.put(keys[i], self._cache_view(o))
-                if self._shared is not None:
-                    self._shared.put(keys[i], o)   # certified-only inside
-        for i, j in dup_of.items():
-            # a distinct outcome per position, so mutating one entry
-            # cannot leak into its duplicates (or the cache)
-            results[i] = detached(self._cache_view(results[j]),
-                                  {**results[j].stats, "cached": True})
-        return results  # type: ignore[return-value]
+                taus = np.zeros((n,), dtype=np.float32)
+
+            results: List[Optional[GedOutcome]] = [None] * n
+            run_idx = list(range(n))
+            keys: List[Optional[tuple]] = [None] * n
+            dup_of: Dict[int, int] = {}
+            if self._cache is not None or self._shared is not None:
+                run_idx, seen = [], {}
+                for i, (q, g) in enumerate(pairs):
+                    keys[i] = pair_key(
+                        q, g, verification,
+                        float(taus[i]) if verification else None, cfg,
+                        self.backend, digest=self.digest)
+                    if keys[i] in seen:
+                        # duplicate within this batch: runs once, answers twice
+                        dup_of[i] = seen[keys[i]]
+                        if self._cache is not None:
+                            self._cache.hits += 1
+                        continue
+                    hit = self._cache.get(keys[i]) \
+                        if self._cache is not None else None
+                    if hit is None and self._shared is not None:
+                        # the cross-process tier answers in-memory misses;
+                        # promote hits so this process stops paying disk
+                        hit = self._shared.get(keys[i])
+                        if hit is not None and self._cache is not None:
+                            self._cache.put(keys[i], self._cache_view(hit))
+                    if hit is not None:
+                        results[i] = hit
+                    else:
+                        seen[keys[i]] = i
+                        run_idx.append(i)
+
+            run_span.set_metadata(pairs=n, run=len(run_idx))
+            if run_idx:
+                plan = build_plan(
+                    [pairs[i] for i in run_idx], slots=self.slots,
+                    vocab=vocab if vocab is not None else self.vocab,
+                    batch_multiple=self.batch_multiple)
+                dl = deadline_s if deadline_s is not None else self.deadline_s
+                pp = (per_pair_deadline_s if per_pair_deadline_s is not None
+                      else self.per_pair_deadline_s)
+                ctx = RunContext(
+                    deadline=dl if isinstance(dl, Deadline) else Deadline(dl),
+                    per_pair_deadline_s=pp,
+                    injector=self._injector, retry=self._retry)
+                if self._backend_takes_ctx:
+                    outs = self._backend.run(plan, taus[run_idx], verification,
+                                             cfg, ctx=ctx)
+                else:
+                    outs = self._backend.run(plan, taus[run_idx], verification,
+                                             cfg)
+                for k, v in ctx.stats.items():
+                    self._fault_stats[k] = self._fault_stats.get(k, 0) + v
+                for i, o in zip(run_idx, outs):
+                    results[i] = o
+                    # never cache a timed-out or fault-degraded *uncertified*
+                    # answer: a later, unconstrained run must not be poisoned
+                    # by this run's budget or faults (degraded-but-certified
+                    # answers are bit-identical, so they stay cacheable)
+                    if o.timed_out or (not o.certified
+                                       and o.stats.get("degraded")):
+                        continue
+                    if self._cache is not None:
+                        self._cache.put(keys[i], self._cache_view(o))
+                    if self._shared is not None:
+                        self._shared.put(keys[i], o)   # certified-only inside
+            for i, j in dup_of.items():
+                # a distinct outcome per position, so mutating one entry
+                # cannot leak into its duplicates (or the cache)
+                results[i] = detached(self._cache_view(results[j]),
+                                      {**results[j].stats, "cached": True})
+            return results  # type: ignore[return-value]
 
     def _cache_view(self, outcome: GedOutcome) -> GedOutcome:
         """What a cache (or in-batch duplicate) may reuse of ``outcome``.

@@ -46,6 +46,7 @@ from repro.ged.exec import (Executor, PendingBatch, ShardedExecutor,
                             engine_outcome)
 from repro.ged.plan import Bucket, Plan
 from repro.ged.results import GedOutcome
+from repro.ged.spans import span
 from repro.runtime.scheduler import Batch, GedScheduler, difficulty
 
 
@@ -357,7 +358,11 @@ class AutoBackend:
         eng = ged.GedEngine("auto", mesh=jax.make_mesh((8,), ("data",)),
                             max_in_flight=4)
         outs = eng.verify(pairs, tau=4.0)       # certified, mesh-sharded
-        eng.stats["overlap_saved_s"]            # device time hidden
+        eng.stats["dispatches"]                 # rung batches dispatched
+
+    Each step of the loop opens a host span (``ged.auto.*``,
+    :mod:`repro.ged.spans`), so a profiler trace splits the host time
+    between device programs into packing, waiting and unpacking.
     """
 
     name = "auto"
@@ -375,8 +380,7 @@ class AutoBackend:
         self.max_in_flight = max(1, int(max_in_flight))
         self.stats: Dict[str, float] = {"pairs": 0, "escalated": 0,
                                         "host_solved": 0, "batches": 0,
-                                        "dispatches": 0,
-                                        "overlap_saved_s": 0.0}
+                                        "dispatches": 0}
 
     @property
     def cache(self):
@@ -388,6 +392,11 @@ class AutoBackend:
 
     def run(self, plan: Plan, taus: np.ndarray, verification: bool,
             cfg: EngineConfig, ctx=None) -> List[GedOutcome]:
+        with span("ged.auto.run"):
+            return self._schedule(plan, taus, verification, cfg, ctx)
+
+    def _schedule(self, plan: Plan, taus: np.ndarray, verification: bool,
+                  cfg: EngineConfig, ctx) -> List[GedOutcome]:
         from repro.ged import faults as _faults
         results: List[Optional[GedOutcome]] = [None] * len(plan.pairs)
         diffs = [difficulty(q.n, g.n, q.m, g.m, q.vlabels, g.vlabels,
@@ -398,7 +407,6 @@ class AutoBackend:
         host_queue: List[int] = []          # pairs awaiting the final rung
         dispatchable: "collections.deque" = collections.deque()  # (bucket, rung)
         inflight: "collections.deque[_InFlight]" = collections.deque()
-        last_block_end: Optional[float] = None  # end of last blocking drain
         has_deadline = ctx is not None and ctx.has_deadline
         # Best-so-far admissible bounds per surviving pair, merged across
         # rungs (anytime contract) — maintained only under a deadline so
@@ -414,9 +422,10 @@ class AutoBackend:
             # final rung: exact host solver (paper-faithful AStar+-BMa)
             q, g = plan.pairs[gi]
             self.stats["host_solved"] += 1
-            o = _robust_host_solve(
-                q, g, float(taus[gi]) if verification else None,
-                verification, cfg, f"{self.name}/exact", -1, ctx)
+            with span("ged.auto.host_solve"):
+                o = _robust_host_solve(
+                    q, g, float(taus[gi]) if verification else None,
+                    verification, cfg, f"{self.name}/exact", -1, ctx)
             if gi in degraded:
                 o.stats["degraded"] = True
             if not o.certified and gi in best:
@@ -460,9 +469,10 @@ class AutoBackend:
                 if self.scheduler.engine_params(batch.rung) is None:
                     host_queue.extend(batch.indices)
                     continue
-                for bucket in plan.subset_buckets(batch.indices,
-                                                  self.executor.pack):
-                    dispatchable.append((bucket, batch.rung))
+                with span("ged.auto.rebucket", rung=batch.rung):
+                    buckets = plan.subset_buckets(batch.indices,
+                                                  self.executor.pack)
+                dispatchable.extend((b, batch.rung) for b in buckets)
 
         def dispatch(bucket: Bucket, rung: int) -> None:
             pool, expand, max_iters = self.scheduler.engine_params(rung)
@@ -489,28 +499,22 @@ class AutoBackend:
             # batch that fails at materialisation is degraded to the host
             # solver, so callers (including the cleanup path below) can
             # always drain in-flight work.
-            nonlocal last_block_end
-            t_drain = time.perf_counter()
             try:
-                out = item.pending.result()  # blocks until the batch lands
+                with span("ged.auto.wait", rung=item.rung):
+                    out = item.pending.result()  # blocks until it lands
             except _faults.CompileRefusal:
                 raise
             except Exception as exc:
-                last_block_end = time.perf_counter()
                 degrade_bucket(item.bucket, exc)
                 return
-            now = time.perf_counter()
+            with span("ged.auto.unpack", rung=item.rung,
+                      rows=len(item.bucket.indices)):
+                unpack(item, out)
+
+        def unpack(item: _InFlight, out: dict) -> None:
             # per-batch wall, not cumulative-since-run-start: a pair's
             # reported wall_s is the cost of the batch that answered it.
-            wall = now - item.t_dispatch
-            # overlap credit: host-side time this batch spent in flight
-            # while we were NOT blocked in another drain — windows are
-            # clipped at the previous blocking call so concurrent batches
-            # never double-count; ~0 in sequential mode.
-            start = item.t_dispatch if last_block_end is None \
-                else max(item.t_dispatch, last_block_end)
-            self.stats["overlap_saved_s"] += max(0.0, t_drain - start)
-            last_block_end = now
+            wall = time.perf_counter() - item.t_dispatch
             survivors = []
             for bi, gi in enumerate(item.bucket.indices):
                 if bool(out["exact"][bi]):

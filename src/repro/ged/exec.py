@@ -46,6 +46,7 @@ from repro.core.exact.graph import Graph
 from repro.kernels import autotune
 from repro.ged.plan import Bucket, CompileCache, Vocab, pack_bucket
 from repro.ged.results import GedOutcome, engine_mapping
+from repro.ged.spans import span
 
 
 # ------------------------------------------------- persistent compile cache
@@ -223,14 +224,15 @@ class Executor:
     >>> ex.batch_multiple
     1
     >>> sorted(ex.stats)
-    ['calls', 'pairs']
+    ['calls', 'pairs', 'rows']
     """
 
     name = "local"
 
     def __init__(self) -> None:
         self.cache = CompileCache()
-        self.stats: Dict[str, float] = {"calls": 0, "pairs": 0}
+        # pairs: real pairs dispatched; rows: the padded batches they rode in
+        self.stats: Dict[str, float] = {"calls": 0, "pairs": 0, "rows": 0}
         self._executables: Dict[tuple, object] = {}
 
     @property
@@ -272,19 +274,24 @@ class Executor:
             out = pending.result()          # numpy dict, blocks if needed
         """
         self._check_batch(packed)
-        # ``use_kernel="auto"`` resolves to a concrete per-bucket kernel
-        # plan *here*, before anything jit-keyed sees the config: the
-        # resolved dispatch (tuning-table lookup or static heuristic for
-        # unmeasured shapes) is pinned on the config, so the jit cache,
-        # the CompileCache ledger and the sharded executor's fn cache all
-        # key on the actual decision.  Outcomes are bit-identical across
-        # dispatch choices, so result caching upstream stays sound.
-        cfg = autotune.resolve_config(cfg, packed.slots, packed.batch)
-        self.cache.record(packed, cfg, verification)
-        self.stats["calls"] += 1
-        self.stats["pairs"] += packed.batch if real is None else int(real)
-        return self._robust_dispatch(packed, taus, cfg, verification,
-                                     ctx, rung)
+        real = packed.batch if real is None else int(real)
+        with span("ged.exec.dispatch", rung=rung, slots=packed.slots,
+                  batch=packed.batch, real=real):
+            # ``use_kernel="auto"`` resolves to a concrete per-bucket
+            # kernel plan *here*, before anything jit-keyed sees the
+            # config: the resolved dispatch (tuning-table lookup or static
+            # heuristic for unmeasured shapes) is pinned on the config, so
+            # the jit cache, the CompileCache ledger and the sharded
+            # executor's fn cache all key on the actual decision.
+            # Outcomes are bit-identical across dispatch choices, so
+            # result caching upstream stays sound.
+            cfg = autotune.resolve_config(cfg, packed.slots, packed.batch)
+            self.cache.record(packed, cfg, verification)
+            self.stats["calls"] += 1
+            self.stats["pairs"] += real
+            self.stats["rows"] += packed.batch
+            return self._robust_dispatch(packed, taus, cfg, verification,
+                                         ctx, rung)
 
     def _robust_dispatch(self, packed, taus, cfg, verification, ctx,
                          rung) -> PendingBatch:
@@ -445,7 +452,9 @@ class Executor:
         """Lower and compile the engine program for ``args``' shapes."""
         from repro.ged.faults import CompileRefusal
         try:
-            return self._lower(static, args).compile()
+            with span("ged.exec.compile", slots=args[0].shape[-1],
+                      batch=args[0].shape[0]):
+                return self._lower(static, args).compile()
         except Exception as exc:
             raise CompileRefusal(
                 f"{self.name} executor: the engine program for "

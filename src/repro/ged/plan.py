@@ -27,6 +27,7 @@ from repro.core.engine.search import EngineConfig
 from repro.core.engine.tensor_graphs import (GraphPairTensors, label_vocab,
                                              pack_pairs)
 from repro.core.exact.graph import Graph
+from repro.ged.spans import span
 
 MIN_SLOTS = 4
 
@@ -171,9 +172,10 @@ def pack_bucket(
     :func:`padded_batch` (the filler repeats the last pair).  Returns
     ``(tensors, real_count)``."""
     real = len(pairs)
-    padded = list(pairs) + [pairs[-1]] * (padded_batch(real, batch_multiple)
-                                          - real)
-    return pack_pairs(padded, slots=slots, vocab=vocab), real
+    batch = padded_batch(real, batch_multiple)
+    with span("ged.pack", slots=slots, real=real, batch=batch):
+        padded = list(pairs) + [pairs[-1]] * (batch - real)
+        return pack_pairs(padded, slots=slots, vocab=vocab), real
 
 
 @dataclasses.dataclass
@@ -260,23 +262,24 @@ def build_plan(
     ``batch_multiple`` — pad every bucket's batch to a multiple of this
     (the executor's shard count; 1 for single-device execution).
     """
-    pairs = as_pairs(raw_pairs)
-    if vocab is None:
-        vocab = label_vocab(pairs)
-    else:
-        vocab = tuple(sorted(int(a) for a in vocab[0])), \
-            tuple(sorted(int(a) for a in vocab[1]))
-    by_slots: Dict[int, List[int]] = {}
-    for i, (q, g) in enumerate(pairs):
-        s = slots if slots is not None else slot_bucket(max(q.n, g.n))
-        by_slots.setdefault(s, []).append(i)
-    buckets = []
-    for s in sorted(by_slots):
-        idxs = by_slots[s]
-        packed, real = pack_bucket([pairs[i] for i in idxs], s, vocab,
-                                   batch_multiple)
-        buckets.append(Bucket(s, idxs, packed, real))
-    return Plan(pairs, buckets, vocab, slots)
+    with span("ged.plan"):
+        pairs = as_pairs(raw_pairs)
+        if vocab is None:
+            vocab = label_vocab(pairs)
+        else:
+            vocab = tuple(sorted(int(a) for a in vocab[0])), \
+                tuple(sorted(int(a) for a in vocab[1]))
+        by_slots: Dict[int, List[int]] = {}
+        for i, (q, g) in enumerate(pairs):
+            s = slots if slots is not None else slot_bucket(max(q.n, g.n))
+            by_slots.setdefault(s, []).append(i)
+        buckets = []
+        for s in sorted(by_slots):
+            idxs = by_slots[s]
+            packed, real = pack_bucket([pairs[i] for i in idxs], s, vocab,
+                                       batch_multiple)
+            buckets.append(Bucket(s, idxs, packed, real))
+        return Plan(pairs, buckets, vocab, slots)
 
 
 # ---------------------------------------------------------- compile cache

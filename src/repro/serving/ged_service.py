@@ -39,6 +39,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import itertools
 import threading
 import time
 from typing import Deque, Dict, List, Optional, Sequence, Tuple
@@ -47,6 +48,7 @@ from repro.core.exact.graph import Graph
 from repro.ged import GedEngine, GedOutcome, GraphStore, SearchHit, as_graph
 from repro.ged.exec import graph_digest
 from repro.ged.faults import Overloaded
+from repro.ged.spans import span
 
 GedResult = GedOutcome  # read-compatible alias (see module docstring)
 
@@ -189,6 +191,9 @@ class GedVerificationService:
         self.scheduler = self.engine._backend.scheduler
         self.store: Optional[GraphStore] = None
         self.admission = AdmissionController(capacity=capacity)
+        # numbers each verify call's host span (next() is atomic, so
+        # concurrent callers never share a number)
+        self._calls = itertools.count(1)
 
     @property
     def stats(self) -> Dict[str, float]:
@@ -258,8 +263,10 @@ class GedVerificationService:
         deadline support, so a deadline-carrying request trades the
         corpus filter's pruning for a hard latency cap.
         """
-        with self.admission.admit(len(requests)):
-            return self._verify_admitted(requests)
+        with span("ged.service.verify", call=next(self._calls),
+                  pairs=len(requests)):
+            with self.admission.admit(len(requests)):
+                return self._verify_admitted(requests)
 
     def _verify_admitted(self, requests: Sequence[GedRequest]
                          ) -> List[GedOutcome]:

@@ -154,7 +154,7 @@ def test_overlap_stats_knobs():
     eng = _tiny_rungs(ged.GedEngine("auto", overlap=True, **OPTS))
     eng.compute(pairs)
     s = eng.stats
-    assert s["overlap_saved_s"] >= 0.0
+    assert s["executor_rows"] >= s["executor_pairs"]
     assert s["dispatches"] > 0 and s["batches"] > 0
     assert "survivors_rung_0" in s
     survivors = sum(v for k, v in s.items()

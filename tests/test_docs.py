@@ -1,6 +1,7 @@
 """The docs suite stays honest: README + docs/ links resolve, the pages
 the README promises exist, and the link checker itself works."""
 
+import re
 import sys
 from pathlib import Path
 
@@ -53,3 +54,13 @@ def test_checker_ignores_code_and_handles_duplicate_headings(tmp_path):
                    "## Example\n## Example\n")
     assert check_file(doc) == []
     assert heading_slugs(doc) == {"example", "example-1"}
+
+
+def test_tracing_section_names_every_span():
+    """The operator docs' span table is ``repro.ged.spans.SPANS``: no span
+    missing, none extra."""
+    from repro.ged.spans import SPANS
+    text = (ROOT / "docs" / "backends.md").read_text()
+    section = text.split("\n## Tracing\n", 1)[1].split("\n## ", 1)[0]
+    named = set(re.findall(r"^\| `(ged\.[a-z_.]+)` \|", section, re.M))
+    assert named == {name for name, _ in SPANS}
