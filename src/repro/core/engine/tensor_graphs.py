@@ -12,12 +12,12 @@ vocabularies ``Lv`` / ``Le``; the per-pair true size ``n`` is data.
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Sequence, Tuple
+from typing import Sequence, Tuple
 
 import numpy as np
 
-from repro.core.exact.graph import BOTTOM, Graph, pad_pair
-from repro.core.exact.order import matching_order
+from repro.core.exact.graph import BOTTOM, Graph, pad_pair, stack_pairs
+from repro.core.exact.order import stacked_orders
 
 
 @dataclasses.dataclass
@@ -80,50 +80,48 @@ def pack_pairs(
     :func:`label_vocab`; when given it must cover every label in the batch
     and is used verbatim so batches packed with the same vocab share the
     compact label space (and hence jit compilations).
+
+    The whole batch is relabelled at once: the distinct labels present are
+    looked up in the sorted vocabulary and mapped back by index.
     """
-    padded: List[Tuple[Graph, Graph]] = []
-    for q, g in pairs:
-        qp, gp, _ = pad_pair(q, g)
-        padded.append((qp, gp))
-
-    # Joint compact label maps across the batch (or the caller's vocab).
-    if vocab is not None:
-        vset, eset = sorted(int(a) for a in vocab[0]), sorted(int(a) for a in vocab[1])
-        observed_v, observed_e = label_vocab(padded)
-        missing = (set(observed_v) - set(vset)) | (set(observed_e) - set(eset))
-        if missing:
-            raise ValueError(f"vocab does not cover batch labels: {sorted(missing)}")
-    else:
-        vset, eset = (list(s) for s in label_vocab(padded))
-    vmap = {a: i for i, a in enumerate(vset)}
-    emap = {a: i + 1 for i, a in enumerate(eset)}
-    emap[0] = 0
-    lv, le = len(vset), len(eset)
-    bottom, pad = lv, lv + 1
-
+    padded = [pad_pair(q, g)[:2] for q, g in pairs]
     nmax = max(gp.n for _, gp in padded)
     if slots is None:
         slots = max(4, int(2 ** np.ceil(np.log2(max(nmax, 1)))))
     if nmax > slots:
         raise ValueError(f"pair with {nmax} vertices does not fit {slots} slots")
 
-    B = len(padded)
-    qv = np.full((B, slots), pad, dtype=np.int32)
-    gv = np.full((B, slots), pad, dtype=np.int32)
-    qa = np.zeros((B, slots, slots), dtype=np.int32)
-    ga = np.zeros((B, slots, slots), dtype=np.int32)
-    order = np.zeros((B, slots), dtype=np.int32)
-    ns = np.zeros((B,), dtype=np.int32)
+    stacked = stack_pairs(padded, slots)
+    qv, gv, qa, ga, ns, _ = stacked
+    verts = np.stack([qv, gv])                    # (2, B, N) as labelled
+    adjs = np.stack([qa, ga])                     # (2, B, N, N)
+    valid = np.arange(slots) < ns[:, None]
+    is_bottom = valid & (verts == BOTTOM)
+    is_real = valid & ~is_bottom
+    is_edge = adjs != 0
+    seen_v, inv_v = np.unique(verts[is_real], return_inverse=True)
+    seen_e, inv_e = np.unique(adjs[is_edge], return_inverse=True)
 
-    for b, (qp, gp) in enumerate(padded):
-        n = gp.n
-        ns[b] = n
-        qv[b, :n] = [bottom if int(a) == BOTTOM else vmap[int(a)] for a in qp.vlabels]
-        gv[b, :n] = [bottom if int(a) == BOTTOM else vmap[int(a)] for a in gp.vlabels]
-        qa[b, :n, :n] = np.vectorize(lambda a: emap[int(a)])(qp.adj)
-        ga[b, :n, :n] = np.vectorize(lambda a: emap[int(a)])(gp.adj)
-        ordv = matching_order(qp, gp)
-        order[b, :n] = ordv
-        order[b, n:] = np.arange(n, slots)  # PAD positions map to themselves
+    # Joint compact label maps across the batch (or the caller's vocab).
+    if vocab is not None:
+        vset = np.sort(np.asarray(vocab[0], dtype=np.int64))
+        eset = np.sort(np.asarray(vocab[1], dtype=np.int64))
+        missing = (set(np.setdiff1d(seen_v, vset).tolist())
+                   | set(np.setdiff1d(seen_e, eset).tolist()))
+        if missing:
+            raise ValueError(f"vocab does not cover batch labels: {sorted(missing)}")
+    else:
+        vset, eset = seen_v, seen_e
+    lv, le = len(vset), len(eset)
+    bottom, pad = lv, lv + 1
 
-    return GraphPairTensors(qv, gv, qa, ga, order, ns, lv, le)
+    # label -> position in the sorted vocabulary (the last of equal entries)
+    vcode = np.full(verts.shape, pad, dtype=np.int32)
+    vcode[is_bottom] = bottom
+    vcode[is_real] = (np.searchsorted(vset, seen_v, side="right") - 1)[inv_v]
+    ecode = np.zeros(adjs.shape, dtype=np.int32)
+    ecode[is_edge] = np.searchsorted(eset, seen_e, side="right")[inv_e]
+
+    order = stacked_orders(*stacked)
+    return GraphPairTensors(vcode[0], vcode[1], ecode[0], ecode[1], order,
+                            ns.astype(np.int32), lv, le)

@@ -111,6 +111,29 @@ def pad_pair(q: Graph, g: Graph) -> Tuple[Graph, Graph, bool]:
     return q, g, swapped
 
 
+def stack_pairs(
+    pairs: Sequence[Tuple[Graph, Graph]], slots: int,
+) -> Tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray, np.ndarray,
+           np.ndarray]:
+    """Stack ``(q, g)`` pairs into arrays of ``slots`` vertices, labels as
+    they are: ``(qv, gv, qa, ga, nq, ng)``, vertex labels ``(B, slots)``
+    and adjacencies ``(B, slots, slots)`` int64, sizes ``(B,)``.  Slots past
+    a graph's size hold ``BOTTOM`` and no edges."""
+    B = len(pairs)
+    qv = np.full((B, slots), BOTTOM, dtype=np.int64)
+    gv = np.full((B, slots), BOTTOM, dtype=np.int64)
+    qa = np.zeros((B, slots, slots), dtype=np.int64)
+    ga = np.zeros((B, slots, slots), dtype=np.int64)
+    nq = np.array([q.n for q, _ in pairs], dtype=np.int64)
+    ng = np.array([g.n for _, g in pairs], dtype=np.int64)
+    for b, (q, g) in enumerate(pairs):
+        qv[b, : q.n] = q.vlabels
+        gv[b, : g.n] = g.vlabels
+        qa[b, : q.n, : q.n] = q.adj
+        ga[b, : g.n, : g.n] = g.adj
+    return qv, gv, qa, ga, nq, ng
+
+
 def editorial_cost(q: Graph, g: Graph, f: Sequence[int]) -> int:
     """Algorithm 1: editorial cost of a full mapping ``f`` (uniform costs).
 
@@ -129,26 +152,3 @@ def editorial_cost(q: Graph, g: Graph, f: Sequence[int]) -> int:
     cost += int(np.count_nonzero(np.triu(q.adj != mapped, k=1)))
     return cost
 
-
-def relabel_compact(q: Graph, g: Graph) -> Tuple[Graph, Graph, int, int]:
-    """Jointly re-map vertex/edge labels of a pair to compact ranges.
-
-    Vertex labels become ``0..Lv-1`` (``BOTTOM`` stays ``BOTTOM``); edge
-    labels become ``1..Le``.  Returns ``(q', g', Lv, Le)``.  Used by the JAX
-    engine, which wants dense histogram bins.
-    """
-    vset = sorted(set(q.vlabels.tolist() + g.vlabels.tolist()) - {BOTTOM})
-    vmap = {a: i for i, a in enumerate(vset)}
-    vmap[BOTTOM] = BOTTOM
-    eset = sorted(
-        (set(np.unique(q.adj).tolist()) | set(np.unique(g.adj).tolist())) - {0}
-    )
-    emap = {0: 0}
-    emap.update({a: i + 1 for i, a in enumerate(eset)})
-
-    def remap(gr: Graph) -> Graph:
-        vl = np.array([vmap[int(a)] for a in gr.vlabels], dtype=np.int64)
-        adj = np.vectorize(lambda a: emap[int(a)])(gr.adj).astype(np.int64)
-        return Graph(vl, adj)
-
-    return remap(q), remap(g), len(vset), len(eset)

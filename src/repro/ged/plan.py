@@ -169,13 +169,17 @@ def pack_bucket(
     batch_multiple: int = 1,
 ) -> Tuple[GraphPairTensors, int]:
     """Pack ``pairs`` at ``slots``, padding the batch dim to
-    :func:`padded_batch` (the filler repeats the last pair).  Returns
-    ``(tensors, real_count)``."""
+    :func:`padded_batch` (the filler rows are copies of the last packed
+    row).  Returns ``(tensors, real_count)``."""
     real = len(pairs)
     batch = padded_batch(real, batch_multiple)
     with span("ged.pack", slots=slots, real=real, batch=batch):
-        padded = list(pairs) + [pairs[-1]] * (batch - real)
-        return pack_pairs(padded, slots=slots, vocab=vocab), real
+        t = pack_pairs(pairs, slots=slots, vocab=vocab)
+        return GraphPairTensors(
+            pad_tail(t.qv, batch), pad_tail(t.gv, batch),
+            pad_tail(t.qa, batch), pad_tail(t.ga, batch),
+            pad_tail(t.order, batch), pad_tail(t.n, batch),
+            t.n_vlabels, t.n_elabels), real
 
 
 @dataclasses.dataclass
